@@ -7,9 +7,11 @@ import jax.numpy as jnp
 
 from repro.core import flop_count, memory_savings_bytes, transpose_conv2d
 from repro.models.gan import GAN_ZOO, generator_flops
+from repro.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     print("# FLOP model — conventional vs segregated MACs")
     print("case,conv_MACs,seg_MACs,reduction")
     for n_in, n_k, pad in [(224, 3, 0), (224, 4, 0), (224, 5, 0),

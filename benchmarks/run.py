@@ -22,8 +22,11 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compile_cache import use_compile_cache
+
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--quick", action="store_true",

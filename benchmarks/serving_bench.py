@@ -55,6 +55,8 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
+
 SERVING_SPEEDUP_FLOOR = 1.3
 OBS_OVERHEAD_CEILING = 1.03   # tracer-off wall vs the serving run's wall
 OBS_WALL_SLACK_S = 0.01       # absolute jitter allowance on tiny walls
@@ -475,6 +477,7 @@ def check(section: dict) -> list[str]:
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="smoke subset: dcgan only, short trace")

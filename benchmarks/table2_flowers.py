@@ -13,6 +13,7 @@ import jax
 
 from repro.core import memory_savings_bytes, transpose_conv2d
 from benchmarks.common import csv_row, rand_image, rand_kernel, time_fn
+from repro.compile_cache import use_compile_cache
 
 GROUPS = {
     "sunflower": 734, "tulip": 984, "daisy": 769, "rose": 784,
@@ -48,6 +49,7 @@ def run(batch=4, groups=None, padding=2):
 
 
 def main():
+    use_compile_cache()
     print("# Table 2 — Flower dataset (CPU, per-dataset seconds)")
     print("group,kernel,conv_s,prop_s,speedup,mem_savings_MB")
     for r in run():

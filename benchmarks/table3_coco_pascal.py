@@ -4,6 +4,7 @@ sample counts."""
 from __future__ import annotations
 
 from benchmarks.table2_flowers import run
+from repro.compile_cache import use_compile_cache
 
 
 DATASETS = {
@@ -14,6 +15,7 @@ DATASETS = {
 
 
 def main():
+    use_compile_cache()
     print("# Table 3 — MSCOCO / PASCAL (CPU, per-dataset seconds)")
     print("dataset,kernel,conv_s,prop_s,speedup")
     for r in run(groups=DATASETS):

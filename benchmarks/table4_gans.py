@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.core import memory_savings_bytes, transpose_conv2d
 from repro.models.gan import GAN_ZOO
 from benchmarks.common import time_fn
+from repro.compile_cache import use_compile_cache
 
 
 METHODS = ("naive", "conventional", "unified", "auto")
@@ -91,6 +92,7 @@ def run_model(cfg):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default="BENCH_transpose_conv.json",
                     help="artifact to merge the table4_train rows into")

@@ -26,6 +26,8 @@ import tempfile
 import time
 from pathlib import Path
 
+from repro.compile_cache import use_compile_cache
+
 
 def bench_training(*, quick: bool) -> dict:
     import jax
@@ -127,6 +129,7 @@ def check(section: dict) -> list[str]:
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="smoke subset: tiny GAN, short run")

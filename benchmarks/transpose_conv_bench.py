@@ -81,6 +81,7 @@ from pathlib import Path
 import jax
 
 from benchmarks.common import time_fn
+from repro.compile_cache import use_compile_cache
 
 FULL_METHODS = (
     "conventional", "unified", "unified_reshape", "unified_matmul",
@@ -743,6 +744,7 @@ def check(result: dict) -> list[str]:
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="smoke subset: dcgan only, 3 methods, 2 repeats")

@@ -20,6 +20,8 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
+
 
 def poisson_trace(models, cfgs, *, rate, n_requests, seed):
     """(requests, arrival offsets): exponential inter-arrivals at ``rate``
@@ -38,6 +40,7 @@ def poisson_trace(models, cfgs, *, rate, n_requests, seed):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--models", default="dcgan",
                     help="comma-separated zoo subset to serve "

@@ -13,6 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.data import SyntheticTokens
 from repro.models.lm import build_model
@@ -21,6 +22,7 @@ BATCH, PROMPT, GEN = 4, 24, 16
 
 
 def main():
+    use_compile_cache()
     cfg = dataclasses.replace(
         get_config("qwen2-0.5b"),
         d_model=256, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=512,

@@ -22,6 +22,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.data import SyntheticImages
 from repro.models import gan
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
@@ -29,6 +30,7 @@ from repro.timing import StepTimer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

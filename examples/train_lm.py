@@ -9,6 +9,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.data import SyntheticTokens
 from repro.models.lm import build_model
@@ -18,6 +19,7 @@ from repro.train.trainer import Trainer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

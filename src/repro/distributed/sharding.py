@@ -56,81 +56,29 @@ def batch_axes() -> tuple:
 
 
 def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh``, portable across JAX versions.
-
-    Newer JAX exposes it under ``jax.sharding``; 0.4.x only has it in
-    ``jax._src.mesh``. Either way an *empty* mesh (no axes) normalizes to
-    ``None`` so callers can treat "no mesh" uniformly. Tests monkeypatch
-    ``jax.sharding.get_abstract_mesh``, which is checked first.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        from jax._src import mesh as mesh_lib
-
-        fn = getattr(mesh_lib, "get_abstract_mesh", None)
-    mesh = fn() if fn is not None else None
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    """The ambient ``jax.sharding.get_abstract_mesh()``, with an *empty*
+    mesh (no axes) normalized to ``None`` so callers can treat "no mesh"
+    uniformly. Tests monkeypatch ``jax.sharding.get_abstract_mesh``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
 
 
 def get_concrete_mesh():
-    """The ambient *device-backed* mesh, or None — what :func:`use_mesh`
-    activates. Prefers the explicit concrete-mesh context, falling back to
-    the 0.4.x thread-resources physical mesh (what a plain ``with mesh:``
-    sets). Distinct from :func:`get_abstract_mesh`: an abstract mesh names
-    axes for the sharding *rules* but carries no devices, so ``shard_map``
-    over real (non-NamedSharding) arrays needs the concrete one.
+    """The ambient *device-backed* mesh, or None — what ``jax.set_mesh``
+    activates. Distinct from :func:`get_abstract_mesh`: an abstract mesh
+    names axes for the sharding *rules* but carries no devices, so
+    ``shard_map`` over real (non-NamedSharding) arrays needs the concrete
+    one. Read through ``jax._src.mesh`` because the public
+    ``jax.sharding.get_mesh`` refuses to run inside a trace.
     """
     from jax._src import mesh as mesh_lib
 
-    fn = getattr(mesh_lib, "get_concrete_mesh", None)
-    mesh = fn() if fn is not None else None
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        env = getattr(mesh_lib, "thread_resources", None)
-        mesh = getattr(getattr(env, "env", None), "physical_mesh", None)
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    mesh = mesh_lib.get_concrete_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
-
-
-def abstract_mesh(axis_sizes: tuple, axis_names: tuple):
-    """Construct an ``AbstractMesh`` across the two historical signatures:
-    ``AbstractMesh(sizes, names)`` (new) vs ``AbstractMesh(((name, size), ...))``
-    (JAX 0.4.x). Used by tests and the dry-run launcher."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for sharded execution, portable
-    across JAX versions (``jax.sharding.use_mesh`` / ``jax.set_mesh`` /
-    ``jax._src.mesh.set_mesh``)."""
-    fn = getattr(jax.sharding, "use_mesh", None) or getattr(
-        jax, "set_mesh", None
-    )
-    if fn is not None:
-        return fn(mesh)
-
-    import contextlib
-
-    from jax._src import mesh as mesh_lib
-
-    @contextlib.contextmanager
-    def _set(mesh):
-        # 0.4.x: activate the mesh WITHOUT the sharding_in_types config flag
-        # that mesh_lib.set_mesh flips (half-built in 0.4.37 — tracing dies
-        # on avals lacking .sharding). The plain `with mesh:` thread-resource
-        # context is what 0.4.x with_sharding_constraint reads.
-        with mesh, mesh_lib.set_abstract_mesh(mesh.abstract_mesh), \
-                mesh_lib.set_concrete_mesh(mesh):
-            yield
-
-    return _set(mesh)
 
 
 def mesh_axis_sizes(mesh) -> dict:
@@ -203,21 +151,6 @@ def shard_batch(x):
     return constrain(x, BATCH, *([None] * (x.ndim - 1)))
 
 
-def _shard_map_fn():
-    """``shard_map`` across its historical homes, with the rep-check kwarg
-    name normalized (``check_rep`` -> ``check_vma`` after 0.4.x)."""
-    import inspect
-
-    try:  # moved to jax.shard_map after 0.4.x
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-    params = inspect.signature(shard_map).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return shard_map, {kw: False}
-
-
 def shard_plan_apply(apply_fn, params, z, plan, *, mesh=None):
     """Run a compiled :class:`repro.kernels.plan.TconvPlan` generator under
     ``shard_map``, batch-sharded over the data-parallel mesh axes.
@@ -253,19 +186,17 @@ def shard_plan_apply(apply_fn, params, z, plan, *, mesh=None):
     if not dp or n_shards <= 0 or z.shape[0] % n_shards:
         return apply_fn(params, z, plan)
 
-    shard_map, no_rep_check = _shard_map_fn()
-
     def local_fn(p, zl):
         return apply_fn(p, zl, plan)
 
     # short specs: shard_map treats missing trailing dims as replicated, so
     # P(dp) means "batch-leading, everything else replicated" for any rank
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(), P(dp)),
         out_specs=P(dp),
-        **no_rep_check,
+        check_vma=False,
     )
     return fn(params, z)
 

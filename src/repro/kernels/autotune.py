@@ -92,6 +92,7 @@ import numpy as np
 
 from repro.core import segregation as seg
 from repro.kernels import epilogue as epilib
+from repro.kernels.mosaic import fits_vmem
 from repro.kernels.transpose_conv2d import default_tiles
 from repro.kernels.transpose_conv2d_bwd import (
     default_bwd_tiles,
@@ -855,7 +856,7 @@ def _tune_fwd(
         transpose_conv2d_pallas, transpose_conv2d_pallas_phase,
     )
     from repro.kernels.transpose_conv2d_gemm import (
-        transpose_conv2d_pallas_gemm,
+        gemm_vmem_bytes, transpose_conv2d_pallas_gemm,
     )
 
     b, n_in, _, cin = x.shape
@@ -937,6 +938,11 @@ def _tune_fwd(
                 for tmv, tnv, tkv in _gemm_tile_variants(
                     b, n_in, n_k, padding, cin, cout
                 ):
+                    if not fits_vmem(gemm_vmem_bytes(
+                        b, n_in, n_k, padding, cin, cout, itemsize,
+                        tiles=(tmv, tnv, tkv),
+                    )):
+                        continue
                     times[(tmv, tnv, tkv)] = _time_fn(
                         jax.jit(
                             lambda *a, _tm=tmv, _tn=tnv, _tk=tkv:
@@ -1177,12 +1183,19 @@ def tune_layer(
     backend = jax.default_backend()
     epilogue = epilib.canonical(epilogue)
     if include_pallas is None:
-        # the Pallas kernels are TPU-lowered (TPU compiler params, Unblocked
+        # the Pallas kernels are TPU-lowered (TPU compiler params, Element
         # indexing); everywhere else they only run interpreted
         include_pallas = backend == "tpu"
+    from repro.kernels.plan import fwd_fits_vmem
+
     methods = tuple(methods or DEFAULT_CANDIDATES)
     lax_methods = tuple(m for m in methods if m not in PALLAS_CANDIDATES)
-    pallas_methods = tuple(m for m in methods if m in PALLAS_CANDIDATES)
+    # the whole-plane kernels race only where they fit VMEM
+    pallas_methods = tuple(
+        m for m in methods if m in PALLAS_CANDIDATES
+        and fwd_fits_vmem(m, b, n_in, n_k, cin, cout, padding,
+                          str(jnp.dtype(dtype)))
+    )
     if not lax_methods and not include_pallas:
         raise ValueError(
             f"nothing to wall-clock: methods={methods} names only Pallas "
@@ -1300,7 +1313,7 @@ def tune_pair(
 
     from repro.kernels.transpose_conv2d import transpose_conv2d_pallas
     from repro.kernels.transpose_conv2d_pair import (
-        transpose_conv2d_pair_pallas,
+        pair_vmem_bytes, transpose_conv2d_pair_pallas,
     )
 
     rng = np.random.default_rng(0)
@@ -1318,6 +1331,11 @@ def tune_pair(
     if "pallas_pair" in methods:
         times = {}
         for tci, tmid, tco in _pair_tile_variants(c0, c1, c2):
+            if not fits_vmem(pair_vmem_bytes(
+                n_in, n_k, c0, c1, c2, padding, itemsize,
+                tiles=(tci, tmid, tco),
+            )):
+                continue
             times[(tci, tmid, tco)] = _time_fn(
                 jax.jit(
                     lambda x, k1, k2, _t=(tci, tmid, tco):
@@ -1418,9 +1436,9 @@ def tune_gan_zoo(
             legal = (
                 c1b == c1
                 and hw2 == seg.output_size(hw1, cfg.kernel, cfg.padding)
-                and pairlib.pair_vmem_bytes(
+                and fits_vmem(pairlib.pair_vmem_bytes(
                     hw1, cfg.kernel, c0, c1, c2, cfg.padding
-                ) <= pairlib.PAIR_VMEM_BUDGET_BYTES
+                ))
             )
             if not legal:
                 i += 1

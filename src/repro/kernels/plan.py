@@ -268,6 +268,32 @@ def _known_fwd(method: str) -> bool:
     return fn is not None and fn is not tc.transpose_conv_auto
 
 
+def fwd_fits_vmem(method: str, b: int, n_in: int, n_k: int, cin: int,
+                  cout: int, padding: int, dtype: str = "float32") -> bool:
+    """Whether forward ``method`` fits the Mosaic VMEM budget at this layer.
+
+    Only the whole-plane kernels (``pallas_phase``, ``pallas_gemm``) are
+    screened: their VMEM grows with the spatial plane. Every other method is
+    bounded by its tiles (or is not a Pallas kernel) and always fits.
+    """
+    import jax.numpy as jnp
+
+    from repro.kernels import mosaic
+
+    itemsize = jnp.dtype(dtype).itemsize
+    if method == "pallas_phase":
+        from repro.kernels.transpose_conv2d import phase_vmem_bytes
+
+        need = phase_vmem_bytes(n_in, n_k, padding, cin, cout, itemsize)
+    elif method == "pallas_gemm":
+        from repro.kernels.transpose_conv2d_gemm import gemm_vmem_bytes
+
+        need = gemm_vmem_bytes(b, n_in, n_k, padding, cin, cout, itemsize)
+    else:
+        return True
+    return mosaic.fits_vmem(need)
+
+
 def plan_layer(
     b: int, n_in: int, n_k: int, cin: int, cout: int, padding: int,
     dtype: str = "float32", *, method: str = "auto", train: bool = False,
@@ -316,6 +342,11 @@ def plan_layer(
         if not _known_fwd(method):
             raise ValueError(f"unknown method {method!r} for LayerPlan")
         resolved = "pallas_fused" if method == "pallas" else method
+        if not fwd_fits_vmem(resolved, b, n_in, n_k, cin, cout, padding, dtype):
+            raise ValueError(
+                f"{resolved} does not fit the VMEM budget at layer "
+                f"b{b} {n_in}x{n_in}x{cin}->{cout} k{n_k} p{padding} {dtype}"
+            )
         if resolved == "pallas_fused" and fwd.get("method") == "pallas_fused":
             tile_h, tile_w = fwd.get("tile_h"), fwd.get("tile_w")
             fuse_epi = fwd.get("fuse_epilogue", True)
@@ -394,9 +425,10 @@ def pair_legal(lp1: LayerPlan, lp2: LayerPlan) -> tuple[bool, str]:
     kernel applies both on fp32 accumulators), the fp32 interface contract,
     and the VMEM budget: the producer's whole output plane + the consumer's
     halo + both sub-kernel stacks must fit
-    :data:`repro.kernels.transpose_conv2d_pair.PAIR_VMEM_BUDGET_BYTES`.
+    :data:`repro.kernels.mosaic.VMEM_BUDGET_BYTES`.
     Returns ``(ok, reason)`` — the reason string names the failed check.
     """
+    from repro.kernels import mosaic
     from repro.kernels import transpose_conv2d_pair as pairlib
 
     if lp1.batch != lp2.batch:
@@ -424,10 +456,9 @@ def pair_legal(lp1: LayerPlan, lp2: LayerPlan) -> tuple[bool, str]:
         lp1.n_in, lp1.n_k, lp1.cin, lp1.cout, lp2.cout, lp1.padding,
         dtype_bytes=2 if lp1.dtype == "bfloat16" else 4,
     )
-    if need > pairlib.PAIR_VMEM_BUDGET_BYTES:
+    if not mosaic.fits_vmem(need):
         return False, (
-            f"VMEM estimate {need} B > budget "
-            f"{pairlib.PAIR_VMEM_BUDGET_BYTES} B"
+            f"VMEM estimate {need} B > budget {mosaic.VMEM_BUDGET_BYTES} B"
         )
     return True, "ok"
 

@@ -29,8 +29,9 @@ therefore needs padded-input rows::
 i.e. an input tile of ``tile_h + dr + (R - 1)`` rows where
 ``dr = max_row0 - min_row0 ∈ {0, 1}`` is the cross-phase origin skew and
 ``R - 1`` is the sub-kernel halo (``R = ceil(n/2)``). Consecutive spatial
-tiles *overlap* by the halo — expressed with an **Unblocked** input BlockSpec
-whose index map returns element offsets ``(b, min_row0 + i*tile_h, ...)``.
+tiles *overlap* by the halo — expressed with an all-**Element** input
+BlockSpec whose index map returns element offsets
+``(b, min_row0 + i*tile_h, ...)`` (:mod:`repro.kernels.mosaic`).
 Per grid step the input load is the halo'd tile only — never the full
 ``(N, N)`` plane — so VMEM stays bounded in ``N`` and each input element is
 loaded once for all four phases: 4x the arithmetic intensity of the
@@ -40,10 +41,11 @@ The four sub-kernels are zero-padded to the common ``R x R`` shape and
 stacked to ``(4, R, R, Cin, Cout)``; the whole stack rides in VMEM and the
 output-parity -> sub-kernel selection (including the odd-padding swap,
 paper §3.4) is a static Python index into it. The output block is the
-interleaved ``(1, tile_h, 2, tile_w, 2, ct)`` slab of the
-``(B, Hp, 2, Wp, 2, Cout)`` layout whose trailing parity axes make the
-stride-2 interleave a contiguous reshape — the upsampled bed-of-nails
-buffer is never materialized.
+``(1, 2, 2, tile_h, tile_w, ct)`` slab of the ``(B, 2, 2, Hp, Wp, Cout)``
+parity-plane layout: each plane is a dense ``(rows, cols, C)`` block that
+Mosaic tiles without padding, and one XLA transpose interleaves the planes
+(:func:`interleave_planes`) — the upsampled bed-of-nails buffer is never
+materialized.
 
 Inputs may be ``bf16`` (or ``fp32``); every tap is an MXU matmul with
 ``preferred_element_type=float32``, so accumulation is always fp32.
@@ -59,13 +61,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are optional (interpret mode ignores them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - non-TPU builds of pallas
-    pltpu = None
-
 from repro.core import segregation as seg
 from repro.kernels import epilogue as epilib
+from repro.kernels.mosaic import (
+    compiler_params, halo_block, pipelined_bytes, tile_offset,
+)
 
 
 def _phase_offsets(n_in: int, n_k: int, padding: int):
@@ -78,6 +78,17 @@ def _phase_offsets(n_in: int, n_k: int, padding: int):
     row0s = (plans[0].row0, plans[2].row0)  # by output row parity
     col0s = (plans[0].col0, plans[1].col0)  # by output col parity
     return row0s, col0s, pad_lo
+
+
+def interleave_planes(out):
+    """(B, 2, 2, Hp, Wp, C) parity planes -> (B, 2Hp, 2Wp, C) output map.
+
+    The kernels write each output-parity plane as a dense (rows, cols, C)
+    block, the layout Mosaic tiles well; the stride-2 interleave is one XLA
+    transpose here.
+    """
+    b, _, _, hp, wp, c = out.shape
+    return out.transpose(0, 3, 1, 4, 2, 5).reshape(b, 2 * hp, 2 * wp, c)
 
 
 def default_tiles(n_in: int, n_k: int, padding: int, cin: int, cout: int):
@@ -124,15 +135,13 @@ def _fused_kernel(x_ref, w_ref, *rest, R, th, tw, roffs, coffs, wsels, epi):
                         window, wk[p, q], preferred_element_type=jnp.float32
                     )
             planes.append(acc.reshape(th, tw, ct))
-    # (pr, pc, t, u, c) -> interleaved block (1, t, pr, u, pc, c)
-    block = jnp.stack(planes).reshape(2, 2, th, tw, ct)
-    block = block.transpose(2, 0, 3, 1, 4)[None]
 
     @pl.when(ci == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += block
+    for ph, plane in enumerate(planes):  # block (1, 2, 2, th, tw, ct)
+        o_ref[0, ph // 2, ph % 2] += plane
 
     if epi is not None:
         @pl.when(ci == pl.num_programs(4) - 1)
@@ -216,29 +225,17 @@ def transpose_conv2d_pallas(
         2 * ((pr + padding) % 2) + ((pc + padding) % 2)
         for pr in range(2) for pc in range(2)
     )
-    grid = (b, n_h, n_w, cout // ct, cin // ci)
-    compiler_params = None
-    if pltpu is not None:
-        # renamed TPUCompilerParams -> CompilerParams in newer JAX
-        params_cls = getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )
-        if params_cls is not None:
-            compiler_params = params_cls(
-                dimension_semantics=(
-                    "parallel", "parallel", "parallel", "parallel",
-                    "arbitrary",
-                ),
-            )
+    n_ci = cin // ci
+    grid = (b, n_h, n_w, cout // ct, n_ci)
     in_specs = [
-        # halo'd spatial tile: overlapping windows -> Unblocked indexing
-        # (index map returns ELEMENT offsets, not block indices)
+        # halo'd spatial tile: overlapping windows -> Element block dims
+        # (the index map returns ELEMENT offsets, not block indices)
         pl.BlockSpec(
-            (1, th + dr + R - 1, tw + dc + R - 1, ci),
+            halo_block(1, th + dr + R - 1, tw + dc + R - 1, ci),
             lambda bb, ih, iw, co, cc: (
-                bb, base_r + ih * th, base_c + iw * tw, cc * ci
+                bb, base_r + ih * th, base_c + tile_offset(iw, tw, n_w),
+                tile_offset(cc, ci, n_ci),
             ),
-            indexing_mode=pl.unblocked,
         ),
         pl.BlockSpec(
             (4, R, R, ci, ct),
@@ -264,14 +261,16 @@ def transpose_conv2d_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, th, 2, tw, 2, ct),
-            lambda bb, ih, iw, co, cc: (bb, ih, 0, iw, 0, co),
+            (1, 2, 2, th, tw, ct),
+            lambda bb, ih, iw, co, cc: (bb, 0, 0, ih, iw, co),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hp, 2, wp, 2, cout), jnp.float32),
-        compiler_params=compiler_params,
+        out_shape=jax.ShapeDtypeStruct((b, 2, 2, hp, wp, cout), jnp.float32),
+        compiler_params=compiler_params(
+            "parallel", "parallel", "parallel", "parallel", "arbitrary",
+        ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(b, 2 * hp, 2 * wp, cout)[:, :m, :m, :]
+    return interleave_planes(out)[:, :m, :m, :]
 
 
 # --------------------------------------------------------------------------
@@ -287,30 +286,31 @@ def _phase_kernel(x_ref, w_ref, *rest, R, Hp, Wp, row0s, col0s, epi):
     o_ref = rest[-1]
     ph = pl.program_id(1)
     ci = pl.program_id(3)
-    pr, pc = ph // 2, ph % 2
-    row0 = jnp.where(pr == 0, row0s[0], row0s[1])
-    col0 = jnp.where(pc == 0, col0s[0], col0s[1])
-
-    x = x_ref[0]  # (Np, Np, Ci) VMEM tile
-    # One dynamic shift per phase; taps below are static slices of this view.
-    xph = jax.lax.dynamic_slice(
-        x, (row0, col0, 0), (Hp + R - 1, Wp + R - 1, x.shape[-1])
-    )
     ct = o_ref.shape[-1]
-    acc = jnp.zeros((Hp * Wp, ct), jnp.float32)
-    for p in range(R):
-        for q in range(R):
-            window = xph[p : p + Hp, q : q + Wp, :].reshape(Hp * Wp, -1)
-            acc += jnp.dot(
-                window, w_ref[0, p, q], preferred_element_type=jnp.float32
-            )
-    acc = acc.reshape(1, Hp, 1, Wp, 1, ct)
 
     @pl.when(ci == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += acc
+    # Mosaic has no dynamic_slice: one static-origin branch per phase, the
+    # grid's phase index picks which one runs
+    for pr in range(2):
+        for pc in range(2):
+            @pl.when(ph == 2 * pr + pc)
+            def _accumulate(row0=row0s[pr], col0=col0s[pc]):
+                x = x_ref[0]  # (Np, Np, Ci) VMEM tile
+                acc = jnp.zeros((Hp * Wp, ct), jnp.float32)
+                for p in range(R):
+                    for q in range(R):
+                        window = x[
+                            row0 + p : row0 + p + Hp,
+                            col0 + q : col0 + q + Wp, :,
+                        ].reshape(Hp * Wp, -1)
+                        acc += jnp.dot(
+                            window, w_ref[0, p, q],
+                            preferred_element_type=jnp.float32,
+                        )
+                o_ref[...] += acc.reshape(1, 1, 1, Hp, Wp, ct)
 
     if epi is not None:
         @pl.when(ci == pl.num_programs(3) - 1)
@@ -319,6 +319,23 @@ def _phase_kernel(x_ref, w_ref, *rest, R, Hp, Wp, row0s, col0s, epi):
             if b_ref is not None:
                 y = y + b_ref[0]
             o_ref[...] = epi.apply_act(y)
+
+
+def phase_vmem_bytes(n_in: int, n_k: int, padding: int, cin: int,
+                     cout: int, dtype_bytes: int = 4) -> int:
+    """VMEM estimate of one per-phase grid step at the default channel
+    tiles: the whole padded input plane, one sub-kernel block, the bias
+    block and the fp32 output plane, all double-buffered."""
+    m = seg.output_size(n_in, n_k, padding)
+    R = seg.ceil_half(n_k)
+    hp = (m + 1) // 2
+    row0s, col0s, pad_lo = _phase_offsets(n_in, n_k, padding)
+    np_ = max(n_in + pad_lo, max(row0s + col0s) + hp + R - 1)
+    ci, ct = min(cin, 512), min(cout, 128)
+    return pipelined_bytes(
+        ((np_, np_, ci), dtype_bytes), ((R, R, ci, ct), dtype_bytes),
+        ((1, ct), 4), ((hp, hp, ct), 4),
+    )
 
 
 @functools.partial(
@@ -400,10 +417,13 @@ def transpose_conv2d_pallas_phase(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, Hp, 1, Wp, 1, ct),
-            lambda bb, ph, co, cc: (bb, 0, ph // 2, 0, ph % 2, co),
+            (1, 1, 1, Hp, Wp, ct),
+            lambda bb, ph, co, cc: (bb, ph // 2, ph % 2, 0, 0, co),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, Hp, 2, Wp, 2, cout), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 2, 2, Hp, Wp, cout), jnp.float32),
+        compiler_params=compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary",
+        ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(b, 2 * Hp, 2 * Wp, cout)[:, :m, :m, :]
+    return interleave_planes(out)[:, :m, :m, :]

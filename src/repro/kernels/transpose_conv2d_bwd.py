@@ -66,25 +66,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are optional (interpret mode ignores them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - non-TPU builds of pallas
-    pltpu = None
-
 from repro.core import segregation as seg
 from repro.kernels import epilogue as epilib
+from repro.kernels.mosaic import compiler_params, halo_block, tile_offset
 from repro.kernels.transpose_conv2d import _phase_offsets
-
-
-def _compiler_params(semantics):
-    if pltpu is None:
-        return None
-    params_cls = getattr(
-        pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-    )
-    if params_cls is None:
-        return None
-    return params_cls(dimension_semantics=semantics)
 
 
 def _wsels(padding: int):
@@ -149,8 +134,11 @@ def default_dw_tile(n_in: int, n_k: int, padding: int) -> int:
 # ------------------------------------------------------- epilogue prologue
 
 def _epilogue_grad_kernel(g_ref, y_ref, o_ref, *, epi):
-    """One (batch, row_tile) grid step: ``gm = g * act'(y)`` elementwise."""
-    o_ref[...] = epi.grad_from_y(g_ref[...], y_ref[...])
+    """One (batch, row_tile) grid step: ``gm = g * act'(y)`` elementwise,
+    in fp32 (the v5e vector unit has no bf16 compare)."""
+    gm = epi.grad_from_y(g_ref[...].astype(jnp.float32),
+                         y_ref[...].astype(jnp.float32))
+    o_ref[...] = gm.astype(o_ref.dtype)
 
 
 def epilogue_grad_pallas(
@@ -197,7 +185,7 @@ def _epilogue_grad_call(g, y, epi, *, tile_m=None, interpret=None):
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
-        compiler_params=_compiler_params(("parallel", "parallel")),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(g, y)
     return out[:, :m]
@@ -302,7 +290,8 @@ def transpose_conv2d_dx_pallas(
     if cin % ci_t or cout % co_t:
         raise ValueError(f"cin={cin} % {ci_t} or cout={cout} % {co_t} != 0")
 
-    grid = (b, n_h, n_w, cin // ci_t, cout // co_t)
+    n_co = cout // co_t
+    grid = (b, n_h, n_w, cin // ci_t, n_co)
     out = pl.pallas_call(
         functools.partial(
             _dx_kernel, R=R, th=th, tw=tw, wsels=_wsels(padding)
@@ -310,13 +299,13 @@ def transpose_conv2d_dx_pallas(
         grid=grid,
         in_specs=[
             # halo'd tile of all four (pre-shifted) parity planes: overlapping
-            # windows -> Unblocked indexing (element offsets)
+            # windows -> Element block dims (element offsets)
             pl.BlockSpec(
-                (4, 1, th + R - 1, tw + R - 1, co_t),
+                halo_block(4, 1, th + R - 1, tw + R - 1, co_t),
                 lambda bb, ih, iw, cc, oc: (
-                    0, bb, ih * th, iw * tw, oc * co_t
+                    0, bb, ih * th, tile_offset(iw, tw, n_w),
+                    tile_offset(oc, co_t, n_co),
                 ),
-                indexing_mode=pl.unblocked,
             ),
             pl.BlockSpec(
                 (4, R, R, co_t, ci_t),
@@ -330,8 +319,8 @@ def transpose_conv2d_dx_pallas(
         out_shape=jax.ShapeDtypeStruct(
             (b, n_h * th, n_w * tw, cin), jnp.float32
         ),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "parallel", "parallel", "arbitrary")
+        compiler_params=compiler_params(
+            "parallel", "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
     )(gs, wt)
@@ -465,7 +454,8 @@ def transpose_conv2d_dw_pallas(
     # is parallel — the db accumulator block is revisited across the cin
     # axis (it accumulates only on the first cin tile), so cin joins
     # (batch, h_tile) as a sequential axis
-    grid = (cout // co_t, cin // ci_t, b, n_h)
+    n_ci = cin // ci_t
+    grid = (cout // co_t, n_ci, b, n_h)
     out_specs = [
         # grid-carried accumulator: one block per (cout, cin) tile,
         # revisited by every (batch, h_tile) step
@@ -491,11 +481,12 @@ def transpose_conv2d_dw_pallas(
         ),
         grid=grid,
         in_specs=[
-            # the forward's halo'd input tile (Unblocked element offsets)
+            # the forward's halo'd input tile (Element offsets)
             pl.BlockSpec(
-                (1, th + dr + R - 1, wp + dc + R - 1, ci_t),
-                lambda oc, cc, bb, ih: (bb, base_r + ih * th, base_c, cc * ci_t),
-                indexing_mode=pl.unblocked,
+                halo_block(1, th + dr + R - 1, wp + dc + R - 1, ci_t),
+                lambda oc, cc, bb, ih: (
+                    bb, base_r + ih * th, base_c, tile_offset(cc, ci_t, n_ci)
+                ),
             ),
             pl.BlockSpec(
                 (4, 1, th, wp, co_t),
@@ -506,9 +497,9 @@ def transpose_conv2d_dw_pallas(
         out_shape=tuple(out_shape) if with_db else out_shape[0],
         # only the db accumulator is revisited across the cin axis; without
         # it the cin tiles stay parallel exactly as before
-        compiler_params=_compiler_params(
-            ("parallel", "arbitrary" if with_db else "parallel",
-             "arbitrary", "arbitrary")
+        compiler_params=compiler_params(
+            "parallel", "arbitrary" if with_db else "parallel",
+            "arbitrary", "arbitrary",
         ),
         interpret=interpret,
     )(xp, gz)

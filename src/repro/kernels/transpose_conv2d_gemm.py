@@ -49,13 +49,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are optional (interpret mode ignores them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - non-TPU builds of pallas
-    pltpu = None
-
 from repro.core import segregation as seg
 from repro.kernels import epilogue as epilib
+from repro.kernels.mosaic import block_bytes, compiler_params, pipelined_bytes
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -79,6 +75,27 @@ def default_gemm_tiles(
     tile_n = 128 if cout % 128 == 0 else cout
     tile_k = 512 if cin % 512 == 0 else cin
     return tile_m, tile_n, tile_k
+
+
+def gemm_vmem_bytes(
+    b: int, n_in: int, n_k: int, padding: int, cin: int, cout: int,
+    dtype_bytes: int = 4, tiles: tuple[int, int, int] | None = None,
+) -> int:
+    """VMEM estimate of one grid step: the resident ``(B, N, N, tile_k)``
+    input plane, the weight, bias and fp32 output blocks (all
+    double-buffered), plus the ``(tile_m, B*N*N)`` one-hot gather operand
+    and the gathered slab it produces. The plane and the one-hot grow with
+    ``B*N*N``, which confines this kernel to the small-map head layers."""
+    m = seg.output_size(n_in, n_k, padding)
+    dtm, dtn, dtk = default_gemm_tiles(b, n_in, n_k, padding, cin, cout)
+    tm, tn, tk = tiles or (dtm, dtn, dtk)
+    tm = min(tm, _round_up(b * m * m, 8))
+    return pipelined_bytes(
+        ((b, n_in, n_in, tk), dtype_bytes), ((tk, tn), dtype_bytes),
+        ((1, tn), 4), ((tm, tn), 4),
+    ) + block_bytes((tm, b * n_in * n_in), dtype_bytes) + block_bytes(
+        (tm, tk), dtype_bytes
+    )
 
 
 def _gemm_kernel(
@@ -203,16 +220,6 @@ def transpose_conv2d_pallas_gemm(
 
     wr = kernel.reshape(n_tap, cin, cout)
     grid = (n_m, n_co, n_ci * n_tap)
-    compiler_params = None
-    if pltpu is not None:
-        # renamed TPUCompilerParams -> CompilerParams in newer JAX
-        params_cls = getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )
-        if params_cls is not None:
-            compiler_params = params_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-            )
     in_specs = [
         # full input plane, cin-tiled: constant across the n_tap
         # consecutive k steps that share a cin tile (taps are the fast
@@ -240,7 +247,7 @@ def transpose_conv2d_pallas_gemm(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm, tn), lambda mm, co, kk: (mm, co)),
         out_shape=jax.ShapeDtypeStruct((n_m * tm, cout), jnp.float32),
-        compiler_params=compiler_params,
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*operands)
     return out[:rows].reshape(b, m, m, cout)

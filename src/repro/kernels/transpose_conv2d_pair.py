@@ -46,20 +46,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory-space bindings (VMEM scratch); interpret mode honors them
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - non-TPU builds of pallas
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import segregation as seg
 from repro.kernels import epilogue as epilib
-from repro.kernels.transpose_conv2d import _phase_offsets
-
-# Per-core VMEM is ~16 MB on current TPUs; the pass budgets the pair's
-# resident set (input plane tile, both weight stacks, interface slab,
-# output block) against this with headroom for Mosaic's own staging.
-PAIR_VMEM_BUDGET_BYTES = 12 * 2**20
+from repro.kernels.mosaic import block_bytes, compiler_params, pipelined_bytes
+from repro.kernels.transpose_conv2d import _phase_offsets, interleave_planes
 
 
 def _snap(c: int, t: int) -> int:
@@ -118,22 +110,23 @@ def pair_vmem_bytes(
 ) -> int:
     """Deterministic per-grid-step VMEM residency estimate of the pair kernel.
 
-    Sums the operand blocks exactly as the BlockSpecs below shape them:
-    padded input plane tile, both stacked sub-kernel blocks, the fp32
-    interface scratch slab, the fp32 output block, and the bias blocks.
-    The plan pass fuses a pair iff this fits :data:`PAIR_VMEM_BUDGET_BYTES`.
+    Sums the operand blocks exactly as the BlockSpecs below shape them, in
+    Mosaic's padded tile layout (:mod:`repro.kernels.mosaic`): padded input
+    plane tile, both stacked sub-kernel blocks, the bias blocks and the fp32
+    output planes, all double-buffered, plus the fp32 interface scratch
+    slab. The plan pass fuses a pair iff this passes
+    :func:`repro.kernels.mosaic.fits_vmem`.
     """
     g = pair_geometry(n_in, n_k, padding)
     tci, tmid, tco = tiles or default_pair_tiles(cin, mid, cout)
-    R = g["R"]
-    return (
-        g["np1"] * g["np1"] * tci * dtype_bytes          # input plane tile
-        + 4 * R * R * tci * tmid * dtype_bytes           # producer stack
-        + 4 * R * R * tmid * tco * dtype_bytes           # consumer stack
-        + (2 * g["hp1"]) * (2 * g["hp1"]) * tmid * 4     # interface scratch
-        + (2 * g["hp2"]) * (2 * g["hp2"]) * tco * 4      # output block
-        + (tmid + tco) * 4                               # bias blocks
-    )
+    R, np1, hp1, hp2 = g["R"], g["np1"], g["hp1"], g["hp2"]
+    return pipelined_bytes(
+        ((np1, np1, tci), dtype_bytes),                  # input plane tile
+        ((4, R, R, tci, tmid), dtype_bytes),             # producer stack
+        ((4, R, R, tmid, tco), 4),                       # consumer stack
+        ((1, tmid), 4), ((1, tco), 4),                   # bias blocks
+        ((4, hp2, hp2, tco), 4),                         # output planes
+    ) + block_bytes((2 * hp1, 2 * hp1, tmid), 4)         # interface scratch
 
 
 def _pair_kernel(
@@ -217,14 +210,13 @@ def _pair_kernel(
                             preferred_element_type=jnp.float32,
                         )
                 planes2.append(acc.reshape(hp2, hp2, ct))
-        block2 = jnp.stack(planes2).reshape(2, 2, hp2, hp2, ct)
-        block2 = block2.transpose(2, 0, 3, 1, 4)[None]
 
         @pl.when(mid == 0)
         def _init_out():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        o_ref[...] += block2
+        for ph, plane in enumerate(planes2):  # block (1, 2, 2, hp2, hp2, ct)
+            o_ref[0, ph // 2, ph % 2] += plane
 
         if epi2 is not None:
             @pl.when(mid == pl.num_programs(2) - 1)
@@ -268,11 +260,6 @@ def transpose_conv2d_pair_pallas(
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    if pltpu is None:  # pragma: no cover - requires a pallas build w/o tpu
-        raise RuntimeError(
-            "transpose_conv2d_pair_pallas needs pallas TPU memory-space "
-            "bindings (pltpu.VMEM) for the interface scratch buffer"
-        )
     epi1 = epilib.canonical(epilogue1)
     epi2 = epilib.canonical(epilogue2)
     for name, epi, bias in (("1", epi1, bias1), ("2", epi2, bias2)):
@@ -315,17 +302,6 @@ def transpose_conv2d_pair_pallas(
     )
 
     grid = (b, c2 // tco, c1 // tmid, c0 // tci)
-    compiler_params = None
-    if pltpu is not None:
-        params_cls = getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )
-        if params_cls is not None:
-            compiler_params = params_cls(
-                dimension_semantics=(
-                    "parallel", "parallel", "arbitrary", "arbitrary",
-                ),
-            )
     np1 = g["np1"]
     in_specs = [
         # the producer's full padded input plane (legality bounds N): one
@@ -357,14 +333,16 @@ def transpose_conv2d_pair_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, hp2, 2, hp2, 2, tco),
+            (1, 2, 2, hp2, hp2, tco),
             lambda bb, co, md, cc: (bb, 0, 0, 0, 0, co),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hp2, 2, hp2, 2, c2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 2, 2, hp2, hp2, c2), jnp.float32),
         # the interface slab: a VMEM scratch accumulator, never an HBM
         # operand — this is the buffer the spy test pins
         scratch_shapes=[pltpu.VMEM((2 * hp1, 2 * hp1, tmid), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=compiler_params(
+            "parallel", "parallel", "arbitrary", "arbitrary",
+        ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(b, 2 * hp2, 2 * hp2, c2)[:, :m2, :m2, :]
+    return interleave_planes(out)[:, :m2, :m2, :]

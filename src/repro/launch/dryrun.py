@@ -215,9 +215,7 @@ def _compile(cfg, shape, mesh, arch):
     t0 = time.time()
     batch_abs = input_specs(cfg, shape)
 
-    from repro.distributed.sharding import use_mesh
-
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_abs, o_abs = abstract_train_state(model, train_cfg)
         p_specs = param_specs(p_abs, cfg.fsdp)
         p_shard = jax.tree_util.tree_map(
@@ -271,8 +269,6 @@ def _compile(cfg, shape, mesh, arch):
 def _report(compiled, cfg, shape, mesh, arch, shape_name, timings, verbose):
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # 0.4.x returns [dict], newer a dict
-        cost = cost[0] if cost else None
     walk = hlo_analyze(compiled.as_text())  # trip-count-aware (per chip)
     n_chips = mesh.size
     report = {

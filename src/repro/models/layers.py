@@ -450,7 +450,6 @@ def _moe_shard_map(p, cfg, x):
     reduction on dbrx-132b train_4k)."""
     from jax.sharding import PartitionSpec as P
 
-    shard_map, no_rep_check = sharding._shard_map_fn()
     mesh = sharding.get_abstract_mesh()
     axes = tuple(mesh.axis_names)
     dp = tuple(a for a in ("pod", "data") if a in axes)
@@ -484,7 +483,7 @@ def _moe_shard_map(p, cfg, x):
         out = jax.lax.psum(out, "model")
         return out.reshape(xl.shape)
 
-    out = shard_map(
+    out = jax.shard_map(
         rank_fn,
         mesh=mesh,
         in_specs=(
@@ -493,7 +492,7 @@ def _moe_shard_map(p, cfg, x):
             P("model", f, None), P("model", f, None), P("model", None, f),
         ),
         out_specs=P(dp or None, None, None),
-        **no_rep_check,
+        check_vma=False,
     )(x, top_p.reshape(B, S, k), top_e.reshape(B, S, k), wg, wu, wd)
 
     out = out.reshape(B, S, d)

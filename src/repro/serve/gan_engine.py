@@ -76,7 +76,8 @@ class GenRequest:
     ``latency_s`` (admission → terminal resolution) is measurable for
     every resolved request, not just served ones. ``retries`` counts
     dispatch attempts beyond the first; ``replica`` records which replica
-    (or ``"inline"`` fallback) served the request, when a supervisor did.
+    (or ``"inline"`` fallback) served the request, when a supervisor did,
+    and ``bucket`` the batch bucket it was served in.
     """
 
     model: str
@@ -93,6 +94,7 @@ class GenRequest:
     failed: bool = False
     retries: int = 0
     replica: str | None = None
+    bucket: int = 0            # batch bucket of the dispatch that served it
 
     @property
     def n(self) -> int:
@@ -411,6 +413,7 @@ class GanEngine:
                 r.done = True
                 r.t_done = now
                 r.replica = replica
+                r.bucket = bucket
                 self.metrics.record_completion(r.latency_s, model=name)
                 self.completed.append(r)
                 self._tl(r.rid, "slice", now, model=name, rows=r.n)

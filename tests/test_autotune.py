@@ -588,9 +588,8 @@ def test_pair_roofline_geomean_beats_back_to_back_on_zoo():
     layer_pair_fusion >= 1.2x gate, pinned here shape by shape."""
     import math
 
-    from repro.kernels.transpose_conv2d_pair import (
-        PAIR_VMEM_BUDGET_BYTES, pair_vmem_bytes,
-    )
+    from repro.kernels.mosaic import fits_vmem
+    from repro.kernels.transpose_conv2d_pair import pair_vmem_bytes
     from repro.models.gan import GAN_ZOO, generator_epilogues
 
     ratios = []
@@ -599,8 +598,8 @@ def test_pair_roofline_geomean_beats_back_to_back_on_zoo():
         i = 0
         while i + 1 < len(cfg.layers):
             (hw, c0, c1), (_, _, c2) = cfg.layers[i], cfg.layers[i + 1]
-            if pair_vmem_bytes(hw, cfg.kernel, c0, c1, c2,
-                               cfg.padding) > PAIR_VMEM_BUDGET_BYTES:
+            if not fits_vmem(pair_vmem_bytes(hw, cfg.kernel, c0, c1, c2,
+                                             cfg.padding)):
                 i += 1
                 continue
             pair_s, _ = autotune.best_pair_proxy(

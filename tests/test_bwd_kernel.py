@@ -133,7 +133,8 @@ def test_dx_blockspec_is_spatially_tiled():
     finally:
         tcb.pl.pallas_call = orig
 
-    ph, b, th, tw, co = captured["in_block"]
+    # halo'd windows are Element block dims (element offsets)
+    ph, b, th, tw, co = (d.block_size for d in captured["in_block"])
     # N=48: default tile_h=8, halo R-1=1 -> 9-row tiles of all 4 planes
     assert ph == 4 and captured["grid"][1] > 1
     assert th < 48 and th <= 8 + 1  # tile + halo, not the plane

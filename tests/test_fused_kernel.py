@@ -100,7 +100,8 @@ def test_input_blockspec_is_spatially_tiled():
     finally:
         tc2d.pl.pallas_call = orig
 
-    b, th, tw, ci = captured["in_block"]
+    # halo'd windows are Element block dims (element offsets)
+    b, th, tw, ci = (d.block_size for d in captured["in_block"])
     # N=48, P=2 -> M=96, Hp=48: default tile_h=8 -> 6 h-tiles, halo R-1=1
     assert captured["grid"][1] > 1 and captured["grid"][2] >= 1
     assert th < 48 and th <= 8 + 1 + 1  # tile + skew + halo, not the plane

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
-from repro.distributed import sharding as sh
 from repro.models import layers as L
 
 
@@ -23,7 +22,7 @@ def test_shard_map_path_equals_reference():
     x = jax.random.normal(jax.random.key(1), (2, 32, cfg.d_model)) * 0.5
     ref_out, ref_aux = L.moe(p, cfg, x)  # no mesh -> reference path
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with sh.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         sm_out, sm_aux = jax.jit(lambda p, x: L.moe(p, cfg, x))(p, x)
     np.testing.assert_allclose(ref_out, sm_out, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(float(ref_aux), float(sm_aux), rtol=1e-5)

@@ -10,7 +10,7 @@ from repro.models.lm import build_model
 
 @pytest.fixture
 def fake_mesh(monkeypatch):
-    mesh = sh.abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     monkeypatch.setattr(
         jax.sharding, "get_abstract_mesh", lambda: mesh, raising=False
     )

@@ -5,6 +5,8 @@ graceful degradation, per-model metrics, and the conservation invariant.
 The chaos-flavored twins (deterministic fault injection through the
 replica dispatch seam) live in ``tests/test_serve_fault_injection.py``.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,6 +72,20 @@ def make_supervisor(cfg, params, *, n_replicas=2, plan=None, clock=None,
     sup.register(cfg, params)
     sup.warmup()
     return sup, inj, clock
+
+def _served_ref(sup, params, r):
+    """Reference output of one served request: the executable that served
+    it (its replica's, or the supervisor's own inline one) at its bucket,
+    run on the request's own latents zero-padded to that bucket. The same
+    program as the batched dispatch, so the comparison is bitwise. (An
+    unbatched program is a different one: XLA:CPU blocks its matmuls by
+    batch size and rounds differently, by about 1e-7.)"""
+    owner = sup if r.replica == "inline" else sup.rslots[r.replica].replica
+    z = np.zeros((r.bucket, np.shape(r.z)[1]), np.float32)
+    z[: r.n] = r.z
+    out = owner._executable(r.model, r.bucket)(params, jnp.asarray(z))
+    return np.asarray(out)[: r.n]
+
 
 
 # --------------------------------------------------------------- replica
@@ -156,8 +172,8 @@ def test_replica_duplicate_register_rejected(tiny_dcgan):
 # ---------------------------------------------------- supervisor: routing
 
 def test_supervisor_outputs_bitwise_equal_across_replicas(tiny_dcgan):
-    """Both replicas serve mixed traffic; every output bitwise-matches the
-    unbatched reference — replicas run the same compiled plans."""
+    """Both replicas serve mixed traffic; every output bitwise-matches its
+    bucket's program — replicas run the same compiled plans."""
     cfg, params = tiny_dcgan
     sup, _, _ = make_supervisor(cfg, params)
     rng = np.random.default_rng(4)
@@ -166,8 +182,13 @@ def test_supervisor_outputs_bitwise_equal_across_replicas(tiny_dcgan):
     sup.serve(reqs)
     assert all(r.done for r in reqs)
     for r in reqs:
-        ref = np.asarray(gan.generator_apply(params, cfg, jnp.asarray(r.z)))
-        assert np.array_equal(np.asarray(r.output), ref)
+        assert np.array_equal(np.asarray(r.output), _served_ref(sup, params, r))
+        # the other replica's program at the same bucket is bitwise-equal
+        other = "r1" if r.replica == "r0" else "r0"
+        assert np.array_equal(
+            _served_ref(sup, params, dataclasses.replace(r, replica=other)),
+            np.asarray(r.output),
+        )
     # both replicas actually took traffic (round-robin balance)
     by_replica = {r.replica for r in reqs}
     assert by_replica == {"r0", "r1"}
@@ -336,8 +357,7 @@ def test_nonfinite_output_never_served(tiny_dcgan):
     assert all(r.done for r in reqs)
     for r in reqs:
         assert np.isfinite(np.asarray(r.output)).all()
-        ref = np.asarray(gan.generator_apply(params, cfg, jnp.asarray(r.z)))
-        assert np.array_equal(np.asarray(r.output), ref)
+        assert np.array_equal(np.asarray(r.output), _served_ref(sup, params, r))
     assert sup.conservation()["ok"]
 
 
@@ -390,8 +410,12 @@ def test_all_dead_inline_fallback_serves_bitwise_equal(tiny_dcgan):
     assert sup.metrics.degraded_batches >= 1
     assert sup.metrics.recompiles >= 1       # the inline compile is visible
     for r in reqs:
-        ref = np.asarray(gan.generator_apply(params, cfg, jnp.asarray(r.z)))
-        assert np.array_equal(np.asarray(r.output), ref)
+        assert np.array_equal(np.asarray(r.output), _served_ref(sup, params, r))
+        # the replicas' program at the same bucket is bitwise-equal too
+        as_r0 = dataclasses.replace(r, replica="r0")
+        assert np.array_equal(
+            np.asarray(r.output), _served_ref(sup, params, as_r0)
+        )
     assert sup.conservation()["ok"]
 
 

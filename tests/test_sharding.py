@@ -17,7 +17,7 @@ def test_constrain_noop_without_mesh():
 def _with_mesh(fn):
     """Run fn with a fake 16x16 production mesh visible to the rule engine
     (set_mesh requires real devices; the rules only read names/sizes)."""
-    mesh = sh.abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     orig = getattr(jax.sharding, "get_abstract_mesh", None)
     jax.sharding.get_abstract_mesh = lambda: mesh
     try:

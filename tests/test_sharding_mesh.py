@@ -86,11 +86,11 @@ def test_batch_is_actually_partitioned():
 
 
 def test_active_mesh_is_picked_up():
-    # mesh=None + an active use_mesh context: shard_plan_apply must find
+    # mesh=None + an active jax.set_mesh context: shard_plan_apply must find
     # the ambient mesh instead of degrading
     params, plan, z, apply_fn = _setup(fuse="off")
     ref = apply_fn(params, z, plan)
-    with sh.use_mesh(_mesh22()):
+    with jax.set_mesh(_mesh22()):
         out = sh.shard_plan_apply(apply_fn, params, z, plan)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=1e-6)
