@@ -268,6 +268,7 @@ def transpose_conv2d_pallas(
         compiler_params=compiler_params(
             "parallel", "parallel", "parallel", "parallel", "arbitrary",
         ),
+        name="tconv_fused",
         interpret=interpret,
     )(*operands)
     return interleave_planes(out)[:, :m, :m, :]
@@ -424,6 +425,7 @@ def transpose_conv2d_pallas_phase(
         compiler_params=compiler_params(
             "parallel", "parallel", "parallel", "arbitrary",
         ),
+        name="tconv_phase",
         interpret=interpret,
     )(*operands)
     return interleave_planes(out)[:, :m, :m, :]

@@ -186,6 +186,7 @@ def _epilogue_grad_call(g, y, epi, *, tile_m=None, interpret=None):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
         compiler_params=compiler_params("parallel", "parallel"),
+        name="tconv_epilogue_grad",
         interpret=interpret,
     )(g, y)
     return out[:, :m]
@@ -322,6 +323,7 @@ def transpose_conv2d_dx_pallas(
         compiler_params=compiler_params(
             "parallel", "parallel", "parallel", "parallel", "arbitrary"
         ),
+        name="tconv_dx",
         interpret=interpret,
     )(gs, wt)
     return out[:, :n_in, :n_in, :]
@@ -501,6 +503,7 @@ def transpose_conv2d_dw_pallas(
             "parallel", "arbitrary" if with_db else "parallel",
             "arbitrary", "arbitrary",
         ),
+        name="tconv_dw",
         interpret=interpret,
     )(xp, gz)
     stack = outs[0] if with_db else outs

@@ -248,6 +248,7 @@ def transpose_conv2d_pallas_gemm(
         out_specs=pl.BlockSpec((tm, tn), lambda mm, co, kk: (mm, co)),
         out_shape=jax.ShapeDtypeStruct((n_m * tm, cout), jnp.float32),
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+        name="tconv_gemm",
         interpret=interpret,
     )(*operands)
     return out[:rows].reshape(b, m, m, cout)

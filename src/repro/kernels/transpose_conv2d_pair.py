@@ -343,6 +343,7 @@ def transpose_conv2d_pair_pallas(
         compiler_params=compiler_params(
             "parallel", "parallel", "arbitrary", "arbitrary",
         ),
+        name="tconv_pair",
         interpret=interpret,
     )(*operands)
     return interleave_planes(out)[:, :m2, :m2, :]

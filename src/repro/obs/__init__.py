@@ -8,13 +8,15 @@ slow request to queue wait vs pack vs kernel wall, say *why* the autotuner
 picked ``pallas_gemm`` over ``pallas_fused`` for a layer, or produce a
 post-mortem artifact when a chaos run kills a replica. ``repro.obs`` is
 that missing leg — production telemetry in the GANAX / HUGE^2 sense
-(unit-level utilization, per-stage decomposition), dependency-free (stdlib
-+ numpy only) and **disabled by default**:
+(unit-level utilization, per-stage decomposition), built on the stdlib,
+numpy and JAX's profiler annotations only, and **disabled by default**:
 
 * :mod:`repro.obs.trace` — process-global :class:`~repro.obs.trace.Tracer`
   with nestable spans, monotonic-clock timestamps, counters/gauges/
-  observation series, and a no-op fast path (one module-level flag check,
-  no lock, no allocation) when tracing is off.
+  observation series, and a no-op fast path (one module-level flag check
+  and one profiler check, no lock, no allocation) when tracing is off.
+  While the JAX profiler records, every span is also written into the
+  profiler's trace, beside the device's operations.
 * :mod:`repro.obs.timeline` — per-request lifecycle timelines for the
   serving path (admit -> queue -> pack -> dispatch -> retry -> slice ->
   reply, one event per ``GenRequest`` state edge), joining the serving

@@ -16,6 +16,15 @@ shared no-op context-manager singleton. The serving bench gates that a
 tracer-off run is within noise of the pre-instrumentation baseline, and
 ``tests/test_obs.py`` pins that a tracer-off run records zero events.
 
+**Two sinks.** While the JAX profiler is recording, every :func:`span`
+also opens a ``jax.profiler.TraceAnnotation`` of the same name (the name
+only; attributes stay in the :class:`Tracer`), whether or not the tracer
+is enabled. The span then lies in the profiler's trace on the profiler's
+clock, beside the device's operations, so an idle gap of the device can be
+put down to the host work that held it. With neither the tracer nor the
+profiler on, :func:`span` costs one flag check and one
+``TraceAnnotation.is_enabled()`` call.
+
 Timestamps are **monotonic-clock** seconds (``time.monotonic`` by
 default; injectable for fake-clock tests), the same clock family the
 serving engine schedules with — so spans, request timelines, and dispatch
@@ -28,6 +37,7 @@ import time
 from collections import deque
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # The module-level disable flag. Read directly (not via a function) by the
 # hot-path helpers below; mutate only through enable()/disable().
@@ -72,13 +82,24 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+# whether the JAX profiler is recording: a static call, no allocation
+_profiling = TraceAnnotation.is_enabled
+
+
+class _ProfilerSpan(TraceAnnotation):
+    """The span handed out when only the profiler records: a
+    ``TraceAnnotation`` that accepts and drops attributes."""
+
+    def set(self, **attrs) -> None:
+        pass
+
 
 class _Span:
     """One live span: a context manager that records itself into its tracer
     on exit. ``set(k=v)`` attaches attributes mid-flight (e.g. the chosen
     replica, the packed bucket)."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "args", "t0", "depth", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -86,11 +107,15 @@ class _Span:
         self.args = args
         self.t0 = 0.0
         self.depth = 0
+        self.annotation = None
 
     def set(self, **attrs) -> None:
         self.args.update(attrs)
 
     def __enter__(self):
+        if _profiling():
+            self.annotation = TraceAnnotation(self.name)
+            self.annotation.__enter__()
         stack = self.tracer._stack()
         self.depth = len(stack)
         stack.append(self)
@@ -105,6 +130,9 @@ class _Span:
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         self.tracer._record_span(self, t1)
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
+            self.annotation = None
         return False
 
 
@@ -262,10 +290,14 @@ def disable() -> None:
 # tracing is off — the instrumented seams call these unconditionally.
 
 def span(name: str, **attrs):
-    """A nestable span context manager (no-op singleton when disabled)."""
-    if not _ENABLED:
-        return NOOP_SPAN
-    return _TRACER.span(name, **attrs)
+    """A nestable span context manager: recorded by the tracer when it is
+    enabled, and in the profiler's trace while the JAX profiler records;
+    the no-op singleton when neither is on."""
+    if _ENABLED:
+        return _TRACER.span(name, **attrs)
+    if _profiling():
+        return _ProfilerSpan(name)
+    return NOOP_SPAN
 
 
 def counter(name: str, inc: float = 1.0) -> None:

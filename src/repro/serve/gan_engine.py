@@ -296,7 +296,6 @@ class GanEngine:
             # by the rid==-1 test) — timeline under a synthetic id
             self._tl(f"reject#{self.metrics.rejected}", "reject", req.t_done,
                      model=req.model, n=n)
-            obs.counter("serve.rejected")
             raise QueueFull(
                 f"queue holds {self.queued_samples} samples, request of {n} "
                 f"exceeds max_queue={self.policy.max_queue}"
@@ -309,7 +308,6 @@ class GanEngine:
                  deadline_s=req.deadline_s)
         self._tl(req.rid, "queue", req.t_submit, depth=len(slot.queue),
                  queued_samples=self.queued_samples)
-        obs.counter("serve.admitted")
         return req.rid
 
     # --------------------------------------------------------------- step
@@ -338,7 +336,6 @@ class GanEngine:
                     )
                     self._tl(r.rid, "expire", now, model=name,
                              residence_s=now - r.t_submit)
-                    obs.counter("serve.expired")
                     dropped += 1
                 else:
                     keep.append(r)
@@ -362,18 +359,19 @@ class GanEngine:
         batch ran."""
         if now is None:
             now = self.clock()
-        self._purge_expired(now)
-        name = self._next_model()
-        if name is None:
-            return False
-        slot = self.registry[name]
-        sizes = [r.n for r in slot.queue]
-        if not drain and not self.policy.should_flush(
-            sizes, now - slot.queue[0].t_submit
-        ):
-            return False
-        count, bucket = self.policy.pack(sizes)
-        reqs = [slot.queue.popleft() for _ in range(count)]
+        with obs.span("serve.schedule"):
+            self._purge_expired(now)
+            name = self._next_model()
+            if name is None:
+                return False
+            slot = self.registry[name]
+            sizes = [r.n for r in slot.queue]
+            if not drain and not self.policy.should_flush(
+                sizes, now - slot.queue[0].t_submit
+            ):
+                return False
+            count, bucket = self.policy.pack(sizes)
+            reqs = [slot.queue.popleft() for _ in range(count)]
         self._execute(name, reqs, bucket)
         return True
 
@@ -419,8 +417,6 @@ class GanEngine:
                 self._tl(r.rid, "slice", now, model=name, rows=r.n)
                 self._tl(r.rid, "reply", now, model=name,
                          latency_s=r.latency_s, replica=replica)
-        obs.counter("serve.completed", len(reqs))
-        obs.observe("serve.batch_wall_s", now - t0)
 
     def _execute(self, name: str, reqs: list, bucket: int) -> None:
         """Pad-and-mask dispatch: pack the requests' latents up to the
@@ -437,7 +433,7 @@ class GanEngine:
         with obs.span("serve.dispatch", model=name, bucket=bucket,
                       n_real=n_real):
             out = self._executable(name, bucket)(slot.params, jnp.asarray(z))
-            out = np.asarray(jax.block_until_ready(out))
+            out = readback(out)
         self._finalize(name, reqs, out, n_real, bucket, t0)
 
     # -------------------------------------------------------- conservation
@@ -500,7 +496,6 @@ class GanEngine:
                     self._tl(f"malformed#{self.metrics.malformed}", "fail",
                              req.t_done, model=getattr(req, "model", None),
                              reason="malformed")
-                    obs.counter("serve.malformed")
                 i += 1
             if self.step():
                 continue
@@ -513,6 +508,15 @@ class GanEngine:
             elif self.queued_requests:
                 self.step(drain=True)   # no more arrivals: flush the tail
         return requests
+
+
+def readback(out) -> np.ndarray:
+    """Wait for a dispatch's output, then copy it to the host. The copy
+    alone is the ``serve.readback`` span, so the wait and the copy of
+    every dispatch can be told apart in a profile."""
+    out = jax.block_until_ready(out)
+    with obs.span("serve.readback"):
+        return np.asarray(out)
 
 
 def sequential_executables(cfg, params, sizes, *, dtype="float32",

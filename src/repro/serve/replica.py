@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import trace as obs
+from repro.serve.gan_engine import readback
 
 
 @dataclasses.dataclass
@@ -172,7 +173,7 @@ class Replica:
                 )
             slot = self.registry[name]
             out = self._executable(name, bucket)(slot.params, jnp.asarray(z))
-            out = np.asarray(jax.block_until_ready(out))
+            out = readback(out)
             if transform is not None:
                 out = transform(out)
             return out
@@ -199,7 +200,7 @@ class Replica:
                 )
             z0 = jnp.zeros((bucket, slot.cfg.z_dim), self.dtype)
             out = self._executable(name, bucket)(slot.params, z0)
-            out = np.asarray(jax.block_until_ready(out))
+            out = readback(out)
             if transform is not None:
                 out = transform(out)
             return bool(np.isfinite(out).all())

@@ -73,12 +73,11 @@ import enum
 import itertools
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import trace as obs
-from repro.serve.gan_engine import GanEngine
+from repro.serve.gan_engine import GanEngine, readback
 from repro.serve.replica import Replica
 
 
@@ -387,7 +386,7 @@ class ReplicaSupervisor(GanEngine):
                 out = self._executable(name, bucket)(
                     slot.params, jnp.asarray(z)
                 )
-                out = np.asarray(jax.block_until_ready(out))
+                out = readback(out)
             except Exception:
                 out = None
             if out is not None and np.isfinite(out).all():

@@ -358,10 +358,10 @@ class GanTrainer:
                             reals, zs = self._batches(step)
                         with obs.span("train.step_fn", step=step):
                             state, metrics = self._step_fn(state, reals, zs)
-                            metrics = jax.device_get(metrics)
+                            # the wait for the step, then the copy
+                            with obs.span("train.sync", step=step):
+                                metrics = jax.device_get(metrics)
                     dt = self.timer.tick()
-                    obs.observe("train.step_s", dt)
-                    obs.counter("train.steps")
                     skipped = int(metrics["skipped"])
                     self.skipped_steps += skipped
                     if self.recorder is not None:
@@ -371,7 +371,6 @@ class GanTrainer:
                             d_loss=float(metrics["d_loss"]),
                         )
                     if skipped:
-                        obs.counter("train.skipped_steps")
                         if self.recorder is not None:
                             self.recorder.dump(
                                 "nan_guard",

@@ -547,10 +547,13 @@ def test_engine_enabled_timelines_complete_and_reconcile(
         seg = tl.segments()
         assert seg["total_s"] >= 0.0 and "execute_s" in seg
     names = iso_tracer.span_names()
-    for expected in ("serve.pack", "serve.dispatch", "serve.slice"):
+    for expected in ("serve.schedule", "serve.pack", "serve.dispatch",
+                     "serve.readback", "serve.slice"):
         assert names.get(expected, 0) >= 1, names
-    assert iso_tracer.counters["serve.admitted"] == 6.0
-    assert iso_tracer.counters["serve.completed"] == 6.0
+    # the counts come from ServeMetrics, published into the registry
+    eng.metrics.publish(iso_tracer)
+    assert iso_tracer.gauges["serve.admitted_total"] == 6.0
+    assert iso_tracer.gauges["serve.requests_total"] == 6.0
 
 
 def test_engine_reject_timeline_synthetic_rid(tiny_engine_parts, iso_tracer):
@@ -630,8 +633,12 @@ def test_trainer_steps_emit_spans_and_observations(iso_tracer):
     names = iso_tracer.span_names()
     assert names.get("train.step") == 2
     assert names.get("train.step_fn") == 2
-    assert iso_tracer.counters["train.steps"] == 2.0
-    assert len(iso_tracer.observations["train.step_s"]) == 2
+    assert names.get("train.sync") == 2
+    # step counts and walls come from the trainer's own summary
+    summary = tr.metrics_summary()
+    assert summary["steps_timed"] == 2
+    assert summary["skipped_steps"] == 0
+    assert len(tr.timer.steps) == 2
 
 
 def test_trainer_nan_guard_dumps_flight_recorder(tmp_path):
