@@ -144,6 +144,8 @@ class GanTrainer:
         self.timer = StepTimer()
         self._stop = False
         self._step_fn = jax.jit(self._build_step(), donate_argnums=(0,))
+        self._z_key = jax.random.key(tcfg.z_seed)
+        self._inputs_fn = jax.jit(self._build_inputs())
 
     # ------------------------------------------------------------- state
 
@@ -276,19 +278,27 @@ class GanTrainer:
 
     # ------------------------------------------------------------ inputs
 
+    def _build_inputs(self):
+        accum, z_shape = self.accum, (self.micro, self.cfg.z_dim)
+
+        def inputs_fn(key, micros, step):
+            """Stack the ``accum`` microbatches and draw their latents,
+            ``normal(fold_in(key, step * accum + j))``; ``step`` is traced,
+            so one executable serves every step."""
+            zs = [jax.random.normal(jax.random.fold_in(key, step * accum + j),
+                                    z_shape)
+                  for j in range(accum)]
+            return jnp.stack(micros), jnp.stack(zs)
+
+        return inputs_fn
+
     def _batches(self, step: int):
         """The step's stacked (accum, micro, ...) inputs, each microbatch a
-        pure function of its flat index ``step * accum + j``."""
-        idx = [step * self.accum + j for j in range(self.accum)]
-        reals = jnp.stack([self.data.batch(i) for i in idx])
-        zs = jnp.stack([
-            jax.random.normal(
-                jax.random.fold_in(jax.random.key(self.tcfg.z_seed), i),
-                (self.micro, self.cfg.z_dim),
-            )
-            for i in idx
-        ])
-        return reals, zs
+        pure function of its flat index ``step * accum + j``: the data
+        source's batches, then one jitted call for the stack and latents."""
+        micros = tuple(self.data.batch(step * self.accum + j)
+                       for j in range(self.accum))
+        return self._inputs_fn(self._z_key, micros, step)
 
     # ------------------------------------------------------- checkpoints
 

@@ -8,6 +8,7 @@ normal-operation contracts."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -45,6 +46,21 @@ def _tree_equal(a, b) -> bool:
         np.array_equal(np.asarray(x), np.asarray(y))
         for x, y in zip(leaves_a, leaves_b)
     )
+
+
+def _eager_inputs(tr, step):
+    """A step's inputs built eagerly, one dispatch per op: the stacked
+    data batches and ``normal(fold_in(key(z_seed), i))`` per flat index."""
+    idx = [step * tr.accum + j for j in range(tr.accum)]
+    reals = jnp.stack([tr.data.batch(i) for i in idx])
+    zs = jnp.stack([
+        jax.random.normal(
+            jax.random.fold_in(jax.random.key(tr.tcfg.z_seed), i),
+            (tr.micro, tr.cfg.z_dim),
+        )
+        for i in idx
+    ])
+    return reals, zs
 
 
 def test_config_validation():
@@ -182,3 +198,34 @@ def test_elastic_resume_across_pod_loss(tmp_path):
     assert tr2.resumed_step == 2
     assert [h["step"] for h in hist] == [2, 3]
     assert all(np.isfinite(h["g_loss"]) for h in hist)
+
+
+@pytest.mark.parametrize("tcfg,accum", [
+    (GanTrainerConfig(global_batch=2), 1),
+    (GanTrainerConfig(global_batch=2, z_seed=2**31 - 3), 1),
+    (GanTrainerConfig(global_batch=4, pods_alive=1, pods_total=2), 2),
+], ids=["accum1", "accum1-large-seed", "elastic-accum2"])
+def test_batches_match_eager_construction(tcfg, accum):
+    """The one jitted input call gives bitwise the reals and latents of the
+    eager construction, at the same flat indices, shapes and dtypes."""
+    tr = _trainer(tcfg)
+    assert tr.accum == accum
+    hw, c = TINY.out_hw(TINY.layers[-1][0]), TINY.layers[-1][2]
+    for step in (0, 1, 3, 1000):
+        reals, zs = tr._batches(step)
+        want_reals, want_zs = _eager_inputs(tr, step)
+        assert reals.shape == (accum, tr.micro, hw, hw, c)
+        assert zs.shape == (accum, tr.micro, TINY.z_dim)
+        assert (reals.dtype, zs.dtype) == (want_reals.dtype, want_zs.dtype)
+        np.testing.assert_array_equal(np.asarray(reals),
+                                      np.asarray(want_reals))
+        np.testing.assert_array_equal(np.asarray(zs), np.asarray(want_zs))
+
+
+def test_inputs_and_step_compile_once():
+    """The step index is a traced argument: four steps leave one compiled
+    entry in the input function and one in the step."""
+    tr = _trainer(GanTrainerConfig(global_batch=2))
+    tr.run(tr.init_state(jax.random.key(0)), steps=4)
+    assert tr._inputs_fn._cache_size() == 1
+    assert tr._step_fn._cache_size() == 1
